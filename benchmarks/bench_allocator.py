@@ -51,6 +51,7 @@ from repro.core import (
     solve_batch,
     tree_index,
 )
+from repro.launch.compile_cache import setup_compile_cache
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 OUT_JSON = ROOT / "BENCH_allocator.json"
@@ -243,6 +244,7 @@ def main(argv=None):
     )
     ap.add_argument("--batch", type=int, default=None)
     args = ap.parse_args(argv)
+    setup_compile_cache()
     batch = args.batch if args.batch is not None else (8 if args.smoke else 16)
     rows, checks = run(quick=args.smoke, batch=batch)
     print(json.dumps(rows[0], indent=2))

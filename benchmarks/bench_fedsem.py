@@ -35,6 +35,7 @@ import platform
 import jax
 
 from repro.core import tree_bits
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.fedsem_e2e import (
     check_backend_equivalence,
     check_noninterference,
@@ -182,6 +183,7 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true", help="tiny CI-sized run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
     rows, checks, perf_checks = run(smoke=args.smoke, seed=args.seed)
     for r in rows:
         print(

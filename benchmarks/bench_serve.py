@@ -51,6 +51,7 @@ import jax
 
 from repro.core import AllocatorConfig, DEFAULT_BUCKETS
 from repro.core.pgd import PGDConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.serve import (
     scenario_stream,
     AllocService,
@@ -419,6 +420,7 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true", help="tiny CI-sized run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    setup_compile_cache()
     rows, checks, perf_checks = run(smoke=args.smoke, seed=args.seed)
     for r in rows:
         print(

@@ -2,7 +2,8 @@
 
 Prints ``name,us_per_call,derived`` CSV lines per benchmark (us_per_call =
 wall time of the benchmark's run; derived = pass/fail summary of the
-paper-claim checks), then a detailed check listing on stderr.
+paper-claim checks), then a detailed check listing on stderr. Exits 1 when a
+benchmark module raised or any of its checks is False.
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--only fig4]
 """
@@ -32,17 +33,22 @@ def _modules():
     }
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="full sweep grids")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
 
     mods = _modules()
     if args.only:
         mods = {k: v for k, v in mods.items() if args.only in k}
 
     all_checks = {}
+    errors = 0
     print("name,us_per_call,derived")
     for name, mod in mods.items():
         t0 = time.time()
@@ -59,9 +65,10 @@ def main() -> None:
             print(f"{name},{dt_us:.0f},checks={n_pass}/{n_check}")
             all_checks[name] = dict(checks)
             all_checks[name].update({f"perf[{k}]": f"INFO:{v}" for k, v in perf.items()})
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report it, run the rest
             print(f"{name},-1,ERROR:{type(e).__name__}")
             all_checks[name] = {"exception": str(e)}
+            errors += 1
 
     print("\n--- paper-claim checks ---", file=sys.stderr)
     failures = 0
@@ -73,7 +80,10 @@ def main() -> None:
             print(f"{name}.{k}: {status}", file=sys.stderr)
     if failures:
         print(f"\n{failures} claim-check failure(s)", file=sys.stderr)
+    if errors:
+        print(f"{errors} benchmark module(s) raised", file=sys.stderr)
+    return 1 if failures or errors else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -397,12 +397,24 @@ def sharded_refine_solver(mesh, weights_batched: bool, acc_batched: bool = False
     (the warm-start sibling of `sharded_batch_solver`: extra starts and the
     base result shard with the scenarios; the accuracy fit shards with them
     when ``acc_batched``, else replicates)."""
-    from .distribute import replicated, scenario_sharding
+    from .distribute import replicated, scenario_map, scenario_sharding
 
     scen = scenario_sharding(mesh)
     rep = replicated(mesh)
+
+    def run(params_batch, weights, acc, extra, base, cfg, weights_batched,
+            acc_batched=False):
+        body = functools.partial(
+            _refine_batch_impl, cfg=cfg, weights_batched=weights_batched,
+            acc_batched=acc_batched,
+        )
+        split = (True, weights_batched, acc_batched, True, True)
+        return scenario_map(body, mesh, split)(
+            params_batch, weights, acc, extra, base
+        )
+
     return jax.jit(
-        _refine_batch_impl,
+        run,
         static_argnames=("cfg", "weights_batched", "acc_batched"),
         in_shardings=(
             scen,
@@ -419,20 +431,29 @@ def sharded_refine_solver(mesh, weights_batched: bool, acc_batched: bool = False
 def sharded_batch_solver(mesh, weights_batched: bool, acc_batched: bool = False):
     """Jitted `solve_batch` body with the scenario axis sharded on ``mesh``.
 
-    Explicit in/out shardings split every leading batch axis over the 1-D
-    scenario mesh (`core.distribute`); the per-scenario solves are independent,
-    so XLA partitions the program with no cross-device communication and each
-    device solves B/mesh.size scenarios. Cached per
+    The vmapped per-scenario body runs under ``jax.shard_map`` over the 1-D
+    scenario mesh (`core.distribute.scenario_map`): each device solves its
+    own B/mesh.size slice — the objective kernel included — and the program
+    holds no cross-device communication. Cached per
     (mesh, weights_batched, acc_batched) — `AllocatorConfig` stays a static
     jit arg, so one cache entry covers every config. The jit object is also
     the serving layer's AOT entry point (``.lower(...).compile()``).
     """
-    from .distribute import replicated, scenario_sharding
+    from .distribute import replicated, scenario_map, scenario_sharding
 
     scen = scenario_sharding(mesh)
     rep = replicated(mesh)
+
+    def run(params_batch, weights, acc, cfg, weights_batched, acc_batched=False):
+        body = functools.partial(
+            _solve_batch_impl, cfg=cfg, weights_batched=weights_batched,
+            acc_batched=acc_batched,
+        )
+        split = (True, weights_batched, acc_batched)
+        return scenario_map(body, mesh, split)(params_batch, weights, acc)
+
     return jax.jit(
-        _solve_batch_impl,
+        run,
         static_argnames=("cfg", "weights_batched", "acc_batched"),
         in_shardings=(
             scen,

@@ -4,9 +4,13 @@
 solves never talk to each other — the batch is embarrassingly parallel. This
 module builds a 1-D ``jax.sharding.Mesh`` over the local devices (axis name
 ``"scenario"``, the `launch/mesh.py` pattern: functions, never module-level
-device state) and the `NamedSharding`s that split that leading axis, so B
-scenarios compile into ONE sharded executable solving B/device_count per
-device with zero cross-device communication.
+device state), the `NamedSharding`s that split that leading axis, and
+`scenario_map`, which maps a batch function over the mesh with
+``jax.shard_map`` — so B scenarios compile into ONE executable in which each
+device solves its own B/device_count slice, Pallas kernels included, with
+zero cross-device communication. (XLA's automatic partitioner cannot split a
+Pallas call, so a plain ``jit`` with in/out shardings does not compile on a
+TPU; the explicit per-device body does.)
 
 Everything works on CPU under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the `launch/dryrun.py`
@@ -52,6 +56,29 @@ def scenario_sharding(mesh: Mesh) -> NamedSharding:
 def replicated(mesh: Mesh) -> NamedSharding:
     """Fully replicated placement on the mesh (broadcast weights, accuracy fit)."""
     return NamedSharding(mesh, PartitionSpec())
+
+
+def scenario_map(fn, mesh: Mesh, split: tuple[bool, ...]):
+    """``jax.shard_map`` of a batch function over the scenario axis.
+
+    ``split[i]`` says whether positional argument ``i`` carries the leading
+    scenario axis (split over the mesh) or is one value for every scenario
+    (replicated: broadcast weights, one accuracy fit). Every output is split
+    on its leading axis. Each device runs ``fn`` on its own B/mesh.size
+    slice, so the per-scenario rows never meet and the program holds no
+    collective — the exact-hardened-X guarantee above.
+    """
+    in_specs = tuple(
+        PartitionSpec(SCENARIO_AXIS) if s else PartitionSpec() for s in split
+    )
+    # check_vma off: the solver's loops start their carries from constants
+    # (bisection brackets, Adam moments) that only become per-device inside
+    # the loop, which the varying-axes type check rejects; nothing here is
+    # ever reduced across devices, so there is nothing for it to protect
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs,
+        out_specs=PartitionSpec(SCENARIO_AXIS), check_vma=False,
+    )
 
 
 def round_up(b: int, multiple: int) -> int:

@@ -116,20 +116,20 @@ def objective_grid_pallas(
 
 def _batch_kernel(
     f_ref, p_ref, r_ref,                # (1, N, BG) candidate tiles
-    rho_ref,                            # (1, BG)
+    rho_ref,                            # (1, 1, BG)
     c_ref, d_ref, D_ref, C_ref, tsc_ref, fmax_ref, mask_ref,  # (1, N, 1)
-    k1_ref, k2_ref, k3_ref, aa_ref, ab_ref,                   # (1, 1)
-    obj_ref,                            # out: (1, BG)
+    k1_ref, k2_ref, k3_ref, aa_ref, ab_ref,                   # (1, 1, 1)
+    obj_ref,                            # out: (1, 1, BG)
     *, xi: float, eta: float, check_feasible: bool,
 ):
     f = f_ref[0]                        # (N, BG)
     p = p_ref[0]
     r = jnp.maximum(r_ref[0], _EPS)
-    rho = rho_ref[...]                  # (1, BG)
+    rho = rho_ref[0]                    # (1, BG)
     real = mask_ref[0] > 0.0            # (N, 1) validity (pad_params contract)
-    k1 = k1_ref[0, 0]
-    k2 = k2_ref[0, 0]
-    k3 = k3_ref[0, 0]
+    k1 = k1_ref[0]                      # (1, 1) runtime scalars
+    k2 = k2_ref[0]
+    k3 = k3_ref[0]
 
     cd = c_ref[0] * d_ref[0]            # (N, 1)
     tau = D_ref[0] / r
@@ -143,9 +143,7 @@ def _batch_kernel(
     t_fl = jnp.max(
         jnp.where(real, tau + t_c, -jnp.inf), axis=0, keepdims=True
     )                                                          # (1, BG)
-    acc = aa_ref[0, 0] * jnp.exp(
-        ab_ref[0, 0] * jnp.log(jnp.maximum(rho, 1e-9))
-    )
+    acc = aa_ref[0] * jnp.exp(ab_ref[0] * jnp.log(jnp.maximum(rho, 1e-9)))
     n_dev = jnp.sum(mask_ref[0], axis=0, keepdims=True)        # (1, 1) real count
 
     obj = (
@@ -161,7 +159,7 @@ def _batch_kernel(
             (f > fmax_ref[0] * (1.0 + 1e-6)) & real, axis=0, keepdims=True
         )
         obj = jnp.where(bad, jnp.inf, obj)
-    obj_ref[...] = obj
+    obj_ref[0] = obj
 
 
 @functools.partial(
@@ -186,21 +184,27 @@ def objective_batch_pallas(
     dimension exactly as in the single-scenario kernel. Weights and the
     accuracy power-law coefficients are *runtime* (B,) inputs, so the same
     compiled kernel serves every `Weights`, including per-scenario batches.
+
+    Every per-scenario operand carries the scenario on a LEADING axis of its
+    own — rho and the output as (B, 1, G), the scalars as (B, 1, 1) — so the
+    last two dims of each block are either whole array dims or lane-aligned,
+    which Mosaic requires for any B (a (1, block_g) block of a (B, G) array
+    is refused unless B == 1). Callers still pass and get back (B, G).
     """
     B, N, G = f_t.shape
     assert G % block_g == 0, "pad G to a multiple of block_g before calling"
     vec = lambda v: jnp.asarray(v, jnp.float32).reshape(B, N, 1)
     scal = lambda v: jnp.broadcast_to(
-        jnp.asarray(v, jnp.float32).reshape(-1, 1), (B, 1)
+        jnp.asarray(v, jnp.float32).reshape(-1, 1, 1), (B, 1, 1)
     )
 
     grid = (B, G // block_g)
     cand_spec = pl.BlockSpec((1, N, block_g), lambda b, i: (b, 0, i))
-    row_spec = pl.BlockSpec((1, block_g), lambda b, i: (b, i))
+    row_spec = pl.BlockSpec((1, 1, block_g), lambda b, i: (b, 0, i))
     vec_spec = pl.BlockSpec((1, N, 1), lambda b, i: (b, 0, 0))
-    scal_spec = pl.BlockSpec((1, 1), lambda b, i: (b, 0))
+    scal_spec = pl.BlockSpec((1, 1, 1), lambda b, i: (b, 0, 0))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
             _batch_kernel, xi=xi, eta=eta, check_feasible=check_feasible
         ),
@@ -209,14 +213,15 @@ def objective_batch_pallas(
             [cand_spec] * 3 + [row_spec] + [vec_spec] * 7 + [scal_spec] * 5
         ),
         out_specs=row_spec,
-        out_shape=jax.ShapeDtypeStruct((B, G), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, 1, G), jnp.float32),
         interpret=interpret,
     )(
         f_t.astype(jnp.float32),
         p_t.astype(jnp.float32),
         r_t.astype(jnp.float32),
-        jnp.asarray(rho, jnp.float32),
+        jnp.asarray(rho, jnp.float32).reshape(B, 1, G),
         vec(c), vec(d), vec(D), vec(C), vec(t_sc_max), vec(f_max),
         vec(dev_mask),
         scal(k1), scal(k2), scal(k3), scal(a_acc), scal(b_acc),
     )
+    return out.reshape(B, G)

@@ -26,7 +26,7 @@ import jax
 from repro.configs.registry import get_config, list_archs
 from repro.launch import roofline as RL
 from repro.launch import specs as SP
-from repro.launch.mesh import HBM_BYTES, make_production_mesh
+from repro.launch.mesh import DRYRUN_KIND, chip_peaks, make_production_mesh
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.parallel import sharding as SH
@@ -218,7 +218,7 @@ def run_pair(arch: str, shape: str, *, multi_pod: bool = False,
             "total": corrected["collective_bytes"],
             "counts": corrected["collective_counts"],
         }
-        rl = RL.roofline(cost, memd, coll)
+        rl = RL.roofline(cost, memd, coll, DRYRUN_KIND)
         rl["xla_cost_analysis_flops_uncorrected"] = float(cost_naive.get("flops", 0.0))
         mf = RL.model_flops(cfg, SP.SHAPES[shape], meta["kind"])
         hlo_global = rl["hlo_flops_per_dev"] * n_chips
@@ -229,7 +229,7 @@ def run_pair(arch: str, shape: str, *, multi_pod: bool = False,
             roofline=rl,
             model_flops_global=mf,
             useful_flops_ratio=(mf / hlo_global) if hlo_global else None,
-            fits_hbm=memd["total_hbm_bytes"] <= HBM_BYTES,
+            fits_hbm=memd["total_hbm_bytes"] <= chip_peaks(DRYRUN_KIND).hbm_bytes,
             hbm_gib=memd["total_hbm_bytes"] / 1024**3,
             collective_counts=coll["counts"],
             swa_variant=SP.uses_swa_variant(cfg, shape),

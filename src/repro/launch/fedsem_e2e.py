@@ -62,6 +62,7 @@ from repro.fl import (
     sample_round_scenarios,
     serve_config_for,
 )
+from repro.launch.compile_cache import setup_compile_cache
 from repro.semcom import AEConfig, init_params
 from repro.serve import AllocService, BatchPolicy, RealClockDriver
 from repro.serve.service import ServeConfig
@@ -281,6 +282,7 @@ def main() -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="CI scale: tiny AE, reduced allocator, 2 jobs")
     args = ap.parse_args()
+    setup_compile_cache()
 
     allocator, serve_cfg, specs, rounds, ae, batch, eval_batch = harness_config(
         args.smoke, args.rounds, args.jobs

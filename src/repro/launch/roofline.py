@@ -1,11 +1,12 @@
 """Roofline extraction from compiled dry-run artifacts (DESIGN.md §7).
 
 All numbers are per-device (the compiled module IS the per-device program
-after SPMD partitioning), so each term is directly a time lower bound:
+after SPMD partitioning), so each term is directly a time lower bound, with
+the chip's peaks looked up by ``device_kind`` (`launch.mesh.PEAKS`):
 
-  compute    = HLO_FLOPs_per_device / 197 TFLOP/s
-  memory     = HLO_bytes_per_device / 819 GB/s
-  collective = collective_bytes_per_device / 50 GB/s per link
+  compute    = HLO_FLOPs_per_device / peak bf16 FLOP/s
+  memory     = HLO_bytes_per_device / HBM bytes/s
+  collective = collective_bytes_per_device / bytes/s per link
 
 collective_bytes is not in cost_analysis(): we parse the post-partitioning
 HLO text and sum the result-shape bytes of every all-gather / all-reduce /
@@ -15,7 +16,7 @@ from __future__ import annotations
 
 import re
 
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from .mesh import chip_peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -64,15 +65,17 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-def roofline(cost: dict, mem: dict, coll: dict) -> dict:
-    """Three-term per-device roofline (seconds) + dominant bottleneck."""
+def roofline(cost: dict, mem: dict, coll: dict, device_kind: str) -> dict:
+    """Three-term per-device roofline (seconds) + dominant bottleneck, on the
+    peaks of one ``device_kind`` chip (unknown kinds raise)."""
+    peaks = chip_peaks(device_kind)
     flops = float(cost.get("flops", 0.0))
     bytes_hbm = float(cost.get("bytes accessed", 0.0))
     bytes_coll = float(coll.get("total", 0))
     terms = {
-        "compute_s": flops / PEAK_FLOPS_BF16,
-        "memory_s": bytes_hbm / HBM_BW,
-        "collective_s": bytes_coll / ICI_BW,
+        "compute_s": flops / peaks.flops_bf16,
+        "memory_s": bytes_hbm / peaks.hbm_bw,
+        "collective_s": bytes_coll / peaks.ici_bw,
     }
     dominant = max(terms, key=terms.get)
     return {

@@ -50,6 +50,7 @@ import jax
 from repro.core import DEFAULT_BUCKETS, AllocatorConfig
 from repro.core.pgd import PGDConfig
 from repro.core.system import feasible
+from repro.launch.compile_cache import setup_compile_cache
 from repro.scenarios import list_families
 from repro.serve import (
     AllocService,
@@ -191,6 +192,7 @@ def main() -> int:
     if args.ladder == "auto" and args.driver != "real":
         ap.error("--ladder auto needs --driver real (online refit lives in "
                  "the real-clock driver's solver thread)")
+    setup_compile_cache()
 
     key = jax.random.PRNGKey(args.seed)
     sizes = ((3, 8), (4, 8)) if args.smoke else ((3, 8), (4, 12), (6, 16))

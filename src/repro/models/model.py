@@ -272,7 +272,7 @@ def _sharded_cross_entropy(logits, labels, mesh, mask=None):
     gemma2's 256k vocab at train_4k) — measured in §Perf. Here each vocab
     shard reduces locally; only (B, S) statistics cross the link.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel.sharding import batch_spec, data_axes
@@ -319,7 +319,7 @@ def _sharded_cross_entropy(logits, labels, mesh, mask=None):
             P(bspec, None),
         ),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(logits, labels, mask_in, m_global)
     return total / jnp.maximum(count, 1)
 

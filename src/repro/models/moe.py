@@ -170,7 +170,7 @@ def moe_ffn_2d(p, cfg, x, mesh, model_axis: str = "model"):
     are not — this removes the per-step FSDP weight all-gathers entirely;
     DESIGN.md §4, §Perf)."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     B, S, d = x.shape
     flat = x.reshape(-1, d)
@@ -208,7 +208,7 @@ def moe_ffn_2d(p, cfg, x, mesh, model_axis: str = "model"):
         shard_fn, mesh=mesh,
         in_specs=(p_specs, P()),        # tokens replicated everywhere
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(p, flat)
     return out.reshape(B, S, d), aux
 
@@ -226,7 +226,7 @@ def moe_ffn(p, cfg, x, mesh=None, model_axis: str = "model"):
         return moe_ffn_2d(p, cfg, x, mesh, model_axis)
 
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_shards = mesh.shape[model_axis]
     e_local = cfg.n_experts // n_shards
@@ -270,6 +270,6 @@ def moe_ffn(p, cfg, x, mesh=None, model_axis: str = "model"):
         shard_fn, mesh=mesh,
         in_specs=(p_specs, P(data_axes if data_axes else None)),
         out_specs=(P(data_axes if data_axes else None), P()),
-        check_rep=False,
+        check_vma=False,
     )(p, flat)
     return out.reshape(B, S, d), aux

@@ -69,7 +69,7 @@ from repro.core.allocator import (
     _solve_batch_jit,
     sharded_refine_solver,
 )
-from repro.core.distribute import replicated
+from repro.core.distribute import scenario_map
 from repro.core.scoring import batch_objectives
 from repro.core.types import DEFAULT_BUCKETS, ShapeBucket
 
@@ -115,6 +115,15 @@ class ServeConfig(NamedTuple):
 #: one fused batched-kernel scoring call per flush; jit-cached per bucket
 #: shape (a tiny program next to the solver executables)
 _score_flush = jax.jit(functools.partial(batch_objectives, weights_batched=True))
+
+
+@functools.lru_cache(maxsize=None)
+def sharded_score_flush(mesh):
+    """`_score_flush` over a scenario mesh: every argument (params, weights,
+    allocation, accuracy fit) is split on its batch axis and each device
+    scores its own rows with the kernel (`core.distribute.scenario_map`)."""
+    score = functools.partial(batch_objectives, weights_batched=True)
+    return jax.jit(scenario_map(score, mesh, (True, True, True, True)))
 
 
 def _round_sig(x: float, digits: int = 12) -> float:
@@ -173,6 +182,9 @@ class AllocService:
         # the sharded executable solves a max_batch-sized sub-batch
         self.mesh = scenario_mesh() if cfg.shard_batch else None
         n_dev = self.mesh.size if self.mesh is not None else 1
+        self._score = (
+            _score_flush if self.mesh is None else sharded_score_flush(self.mesh)
+        )
         self._full_slots = cfg.policy.max_batch * n_dev
         self.batcher = MicroBatcher(cfg.policy._replace(max_batch=self._full_slots))
         self.metrics = ServiceMetrics()
@@ -538,7 +550,7 @@ class AllocService:
         # per-row fits the rows were SOLVED with, so a `set_accuracy` racing
         # an in-flight flush can never mis-report `Completion.objective`
         objs = (
-            np.asarray(_score_flush(pb, wb, res.alloc, accb))
+            np.asarray(self._score(pb, wb, res.alloc, accb))
             if self.cfg.score_objective
             else None
         )

@@ -94,6 +94,18 @@ def test_sharded_solve_batch_matches_single_device():
         assert bool(feasible(tree_index(pb, i), tree_index(got.alloc, i)))
 
 
+def test_sharded_solve_batch_result_spans_the_mesh():
+    """Each device keeps its own slice of the answer: the result is split
+    over every device of the mesh, not gathered onto the first."""
+    mesh = scenario_mesh()
+    pb = sample_params_batch(jax.random.PRNGKey(2), mesh.size * 2, N=4, K=8)
+    got = solve_batch(pb, W, CFG, mesh=mesh)
+    for leaf in jax.tree.leaves(got):
+        homes = {s.device for s in leaf.addressable_shards}
+        assert homes == set(mesh.devices.flat)
+        assert {s.data.shape[0] for s in leaf.addressable_shards} == {2}
+
+
 def test_sharded_solve_batch_pads_non_divisible():
     mesh = scenario_mesh()
     b = mesh.size + 1                        # forces the pad/slice path

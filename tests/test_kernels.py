@@ -232,6 +232,8 @@ def _batch_grid_inputs(key, B, G, N, masked_rows=True):
     (3, 700, 4),     # padded candidate axis (700 -> 768), per-row masks
     (1, 6, 5),       # B=1 degenerate batch, tiny multi-start-sized G
     (8, 1, 6),       # G=1: one allocation per scenario (the serving path)
+    (8, 1, 16),      # the serving flush at the Table-I bucket: 8 slots x N=16
+    (3, 1, 16),      # a scenario count that is not a multiple of 8
 ])
 @pytest.mark.parametrize("feasible_mask", [True, False], ids=["feas", "raw"])
 def test_fedsem_objective_batch_kernel_matches_ref(B, G, N, feasible_mask):
