@@ -36,19 +36,19 @@ from .ladder import (
     LadderLearner, LadderSnapshot, learn_buckets, padded_area_waste,
 )
 from .loadgen import LoadResult, poisson_arrivals, run_load, scenario_stream
-from .metrics import Reservoir, ServiceMetrics, percentile
-from .service import AllocService, Completion, ServeConfig
+from .metrics import Reservoir, ServiceMetrics, percentile, span
+from .service import AllocService, Completion, FlushTiming, ServeConfig
 from .warmstart import (
     CacheEntry, WarmStartCache, WarmStartConfig, batch_starts,
     entry_from_alloc, iters_to_converge, pad_start, request_signature,
 )
 
 __all__ = [
-    "AllocService", "Completion", "ServeConfig",
+    "AllocService", "Completion", "FlushTiming", "ServeConfig",
     "WarmStartCache", "WarmStartConfig", "CacheEntry", "request_signature",
     "entry_from_alloc", "pad_start", "batch_starts", "iters_to_converge",
     "BatchPolicy", "MicroBatcher", "PendingRequest",
-    "ServiceMetrics", "Reservoir", "percentile",
+    "ServiceMetrics", "Reservoir", "percentile", "span",
     "LoadResult", "poisson_arrivals", "run_load", "scenario_stream",
     "AsyncAllocDriver",
     "RealClockDriver", "DriverConfig", "AdmissionQueueFull", "DriverClosed",

@@ -48,6 +48,13 @@ class PendingRequest:
     #: to record the hardened solution after the flush); None when the
     #: service runs without a cache
     warm_sig: tuple | None = None
+    #: seconds `AllocService.prepare` took for this request (its
+    #: ``alloc.prepare`` span)
+    prepare_s: float = 0.0
+    #: when a `RealClockDriver`'s solver thread took the request out of its
+    #: inbox, on the driver clock (``arrival_t`` is the enqueue on the
+    #: caller's thread); None without a driver
+    admit_t: float | None = None
 
 
 class MicroBatcher:

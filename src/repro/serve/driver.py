@@ -48,6 +48,14 @@ shape mix mid-run, and the ladder follows without an operator hook. Swapping
 ladders mid-stream cannot change answers: padding is answer-transparent
 (identical hardened X through any covering bucket), so the real==virtual
 equivalence gate holds across refits.
+
+Both roles are timed by `metrics.span`s: ``alloc.submit`` (> ``alloc.prepare``,
+``alloc.enqueue``) on the caller's thread; ``alloc.idle`` (the solver waiting
+on its inbox), ``alloc.admit``, the service's ``alloc.flush`` and
+``alloc.resolve`` on the solver thread. The solver stamps
+`PendingRequest.admit_t` as it admits, so each `Completion.inbox_s` is the
+request's dwell in the inbox, and ``summary()["solver_idle_s"]`` sums
+``alloc.idle``.
 """
 from __future__ import annotations
 
@@ -63,6 +71,7 @@ import numpy as np
 from repro.core import SystemParams, Weights
 
 from .ladder import LadderLearner, LadderSnapshot
+from .metrics import span
 from .service import AllocService, Completion
 
 _SENTINEL = object()
@@ -200,6 +209,9 @@ class RealClockDriver:
         self._admitted = 0
         self._next_refit_check = cfg.refit_check_every
         self.auto_refits = 0
+        #: seconds the solver thread spent waiting on an empty inbox
+        #: (``alloc.idle``; solver-thread only)
+        self._idle_s = 0.0
         self._closed = threading.Event()
         #: serialises the closed-check-then-enqueue in submit() against
         #: close()'s fence + post-join sweep, so an admission can never land
@@ -245,36 +257,40 @@ class RealClockDriver:
         jobs sharing this driver pass their tenant id so refits never touch
         a co-tenant's requests.
         """
-        if self._closed.is_set():
-            raise DriverClosed("driver is closed; no further admissions")
-        prepared = self.service.prepare(params, weights, warm_start, accuracy, tenant)
-        fut: Future = Future()
-        # re-check + enqueue under the fence: close() flips the flag under
-        # the same lock, so a submit that slept through close() during the
-        # prepare() above raises here instead of enqueueing into a queue
-        # nobody will ever drain again. Backpressure blocking happens inside
-        # the fence too, which serialises blocked submitters — fine, they
-        # were going to wait for the same solver anyway.
-        with self._fence:
+        with span("alloc.submit"):
             if self._closed.is_set():
                 raise DriverClosed("driver is closed; no further admissions")
-            try:
-                self._inbox.put(
-                    (prepared, fut, self.now()),
-                    block=self.cfg.block,
-                    timeout=self.cfg.submit_timeout_s,
-                )
-            except queue.Full:
-                raise AdmissionQueueFull(
-                    f"admission queue full ({self.cfg.queue_capacity} waiting); "
-                    "solver thread is behind — shed load or retry"
-                ) from None
-        if self.ladder is not None:
-            # observe only ADMITTED shapes (after the put): shed/rejected
-            # submits must not skew the learned mix toward traffic that was
-            # never served
-            self.ladder.observe(params.N, params.K)
-        return fut
+            prepared = self.service.prepare(
+                params, weights, warm_start, accuracy, tenant
+            )
+            fut: Future = Future()
+            # re-check + enqueue under the fence: close() flips the flag
+            # under the same lock, so a submit that slept through close()
+            # during the prepare() above raises here instead of enqueueing
+            # into a queue nobody will ever drain again. Backpressure
+            # blocking happens inside the fence too, which serialises blocked
+            # submitters — fine, they were going to wait for the same solver
+            # anyway.
+            with span("alloc.enqueue"), self._fence:
+                if self._closed.is_set():
+                    raise DriverClosed("driver is closed; no further admissions")
+                try:
+                    self._inbox.put(
+                        (prepared, fut, self.now()),
+                        block=self.cfg.block,
+                        timeout=self.cfg.submit_timeout_s,
+                    )
+                except queue.Full:
+                    raise AdmissionQueueFull(
+                        f"admission queue full ({self.cfg.queue_capacity} waiting); "
+                        "solver thread is behind — shed load or retry"
+                    ) from None
+            if self.ladder is not None:
+                # observe only ADMITTED shapes (after the put): shed/rejected
+                # submits must not skew the learned mix toward traffic that
+                # was never served
+                self.ladder.observe(params.N, params.K)
+            return fut
 
     def _cover_must_fit(self, must_fit) -> tuple[tuple[int, int], ...]:
         """Union ``must_fit`` with the current ladder's cover shape so a refit
@@ -394,6 +410,7 @@ class RealClockDriver:
             "queue_capacity": self.cfg.queue_capacity,
             "inflight": len(self._tickets),
             "auto_refits": self.auto_refits,
+            "solver_idle_s": self._idle_s,
         }
         if self.service.warm_cache is not None:
             out.update(self.service.warm_cache.stats())
@@ -406,6 +423,7 @@ class RealClockDriver:
         if item is _SENTINEL:
             return True
         prepared, fut, t_enq = item
+        prepared.admit_t = self.now()
         req_id = self.service.admit(prepared, now=t_enq)
         self._tickets[req_id] = fut
         self._admitted += 1
@@ -422,11 +440,14 @@ class RealClockDriver:
             stop = self._admit_one(item) or stop
 
     def _resolve(self, done: list[Completion]) -> None:
-        for c in done:
-            self.completions.append(c)
-            fut = self._tickets.pop(c.req_id, None)
-            if fut is not None:
-                fut.set_result(c)
+        if not done:
+            return
+        with span("alloc.resolve", n=len(done)):
+            for c in done:
+                self.completions.append(c)
+                fut = self._tickets.pop(c.req_id, None)
+                if fut is not None:
+                    fut.set_result(c)
 
     def _run(self) -> None:
         try:
@@ -452,13 +473,17 @@ class RealClockDriver:
                 if deadline is None
                 else max(0.0, deadline - self.now())
             )
-            try:
-                stop = self._admit_one(self._inbox.get(timeout=timeout))
-            except queue.Empty:
-                pass
-            # burst admission: everything already queued joins this round's
-            # flush decision before any solve starts
-            stop = self._admit_pending() or stop
+            with span("alloc.idle") as idle:
+                try:
+                    item = self._inbox.get(timeout=timeout)
+                except queue.Empty:
+                    item = None
+            self._idle_s += idle.s
+            with span("alloc.admit"):
+                stop = item is not None and self._admit_one(item)
+                # burst admission: everything already queued joins this
+                # round's flush decision before any solve starts
+                stop = self._admit_pending() or stop
             if stop:
                 break
             self._maybe_auto_refit()

@@ -10,13 +10,21 @@ bound. Below the cap the reservoir holds every observation, so percentiles
 are exact; above it, it keeps a uniform random sample (Vitter's Algorithm R,
 deterministically seeded) and percentiles become sample estimates — while
 count / mean / max stay exact running aggregates regardless of volume.
+
+`span` times one stretch of the served path: it returns its wall time to the
+caller (the `Completion` and `FlushTiming` fields, the reservoirs below) and,
+while a `jax.profiler` session runs, puts the same stretch on the host plane
+of the profiler's trace, on the clock of the device's events. Tracing off is
+no profiler session; a span then records nothing but its duration.
 """
 from __future__ import annotations
 
 import dataclasses
 import random
+import time
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 #: default per-metric sample cap: exact percentiles up to this many
 #: observations, ~32 KiB of floats per metric forever after
@@ -30,6 +38,32 @@ def percentile(values, q: float) -> float:
     if not values:
         return float("nan")
     return float(np.percentile(np.asarray(values, dtype=np.float64), q))
+
+
+class span:
+    """``with span(name, **ids) as sp: ...`` — ``sp.s`` is the block's
+    `time.perf_counter` duration once it exits.
+
+    The block is also a `jax.profiler.TraceAnnotation` named ``name``, with
+    ``ids`` as its arguments (the trace shows them as the event's stats, so
+    spans of different threads join by ``flush_id``). Spans nest on their
+    thread. Costs a microsecond or two without a profiler session.
+    """
+
+    __slots__ = ("_note", "_t0", "s")
+
+    def __init__(self, name: str, **ids):
+        self._note = TraceAnnotation(name, **ids)
+        self.s = 0.0
+
+    def __enter__(self) -> "span":
+        self._note.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.s = time.perf_counter() - self._t0
+        self._note.__exit__(*exc)
 
 
 class Reservoir:
@@ -108,6 +142,12 @@ class ServiceMetrics:
     #: solve-iteration-savings evidence `bench_serve` reports
     warm_iters: Reservoir = dataclasses.field(default_factory=Reservoir)
     cold_iters: Reservoir = dataclasses.field(default_factory=Reservoir)
+    #: per request: `AllocService.prepare` (the ``alloc.prepare`` span), and
+    #: the dwell in a driver's inbox before the solver thread admitted it
+    prepare_s: Reservoir = dataclasses.field(default_factory=Reservoir)
+    inbox_s: Reservoir = dataclasses.field(default_factory=Reservoir)
+    #: per flush: ``alloc.flush`` wall time less the solve (`FlushTiming.host_s`)
+    flush_host_s: Reservoir = dataclasses.field(default_factory=Reservoir)
     submitted: int = 0
     completed: int = 0
     batches: int = 0
@@ -126,10 +166,18 @@ class ServiceMetrics:
         self.occupancy.add(n_real / max(slots, 1))
         self.solves_s.add(solve_s)
 
-    def observe_completion(self, latency_s: float, wait_s: float) -> None:
+    def observe_completion(
+        self, latency_s: float, wait_s: float, prepare_s: float = 0.0,
+        inbox_s: float = 0.0,
+    ) -> None:
         self.completed += 1
         self.latencies_s.add(latency_s)
         self.waits_s.add(wait_s)
+        self.prepare_s.add(prepare_s)
+        self.inbox_s.add(inbox_s)
+
+    def observe_flush_host(self, host_s: float) -> None:
+        self.flush_host_s.add(host_s)
 
     def observe_warm(self, hit: bool, iters: int) -> None:
         """Record one completed request's convergence iterations under the
@@ -170,4 +218,7 @@ class ServiceMetrics:
             "warm_misses": self.warm_misses,
             "warm_iters_mean": self.warm_iters.mean(),
             "cold_iters_mean": self.cold_iters.mean(),
+            "prepare_p50_s": self.prepare_s.percentile(50.0),
+            "inbox_p50_s": self.inbox_s.percentile(50.0),
+            "flush_host_mean_s": self.flush_host_s.mean(),
         }

@@ -35,6 +35,14 @@ The service is sans-IO: callers pass ``now`` timestamps and decide when to
 flush (`flush_full` after submits, `flush_due` on timer ticks, `drain` at
 shutdown), which makes it drivable by a real clock (`repro.launch.serve_alloc`)
 or a virtual one (`repro.serve.loadgen`, benchmarks).
+
+Admission and each flush are timed by `metrics.span`s with fixed names
+(``alloc.prepare`` > ``alloc.pad``, ``alloc.warm_lookup``; ``alloc.flush`` >
+``flush.stack``, ``flush.solve``, ``flush.score``, ``flush.unpad``,
+``flush.record``). Their durations land on `PendingRequest.prepare_s`, on
+`Completion.solve_s` (``flush.solve``) and on one `FlushTiming` per flush,
+shared by its `Completion`s; under a `jax.profiler` session they are also
+host events of the trace.
 """
 from __future__ import annotations
 
@@ -74,7 +82,7 @@ from repro.core.scoring import batch_objectives
 from repro.core.types import DEFAULT_BUCKETS, ShapeBucket
 
 from .batching import BatchPolicy, MicroBatcher, PendingRequest
-from .metrics import ServiceMetrics
+from .metrics import ServiceMetrics, span
 from .warmstart import (
     CacheEntry,
     WarmStartCache,
@@ -112,9 +120,21 @@ class ServeConfig(NamedTuple):
     warmstart: WarmStartConfig | None = None
 
 
+def _flush_objectives(params_batch, weights_batch, allocs, acc_batch):
+    """`batch_objectives` of a flush: every argument carries one row per slot."""
+    return batch_objectives(
+        params_batch, weights_batch, allocs, acc_batch, weights_batched=True
+    )
+
+
+# a program takes its function's name: the score program is
+# ``jit_batch_objectives`` in the HLO and in a profiler trace, on one chip
+# and on a mesh
+_flush_objectives.__name__ = _flush_objectives.__qualname__ = "batch_objectives"
+
 #: one fused batched-kernel scoring call per flush; jit-cached per bucket
 #: shape (a tiny program next to the solver executables)
-_score_flush = jax.jit(functools.partial(batch_objectives, weights_batched=True))
+_score_flush = jax.jit(_flush_objectives)
 
 
 @functools.lru_cache(maxsize=None)
@@ -122,8 +142,7 @@ def sharded_score_flush(mesh):
     """`_score_flush` over a scenario mesh: every argument (params, weights,
     allocation, accuracy fit) is split on its batch axis and each device
     scores its own rows with the kernel (`core.distribute.scenario_map`)."""
-    score = functools.partial(batch_objectives, weights_batched=True)
-    return jax.jit(scenario_map(score, mesh, (True, True, True, True)))
+    return jax.jit(scenario_map(_flush_objectives, mesh, (True, True, True, True)))
 
 
 def _round_sig(x: float, digits: int = 12) -> float:
@@ -141,14 +160,35 @@ def _round_sig(x: float, digits: int = 12) -> float:
     return round(x, digits - 1 - math.floor(math.log10(abs(x))))
 
 
+class FlushTiming(NamedTuple):
+    """Where one flush's wall time went beside its solve (`Completion.solve_s`,
+    the ``flush.solve`` span): the other ``flush.*`` spans of its
+    ``alloc.flush``. Shared by every `Completion` of the flush."""
+
+    flush_id: int       # per service, in flush order
+    n_real: int         # requests answered
+    slots: int          # batch slots solved (padding replicates the last)
+    stack_s: float      # stack rows, warm starts, executable lookup, placement
+    score_s: float      # the score program and its host copy
+    unpad_s: float      # every request's exact-shape allocation
+    record_s: float     # warm-cache puts, convergence counts, metrics
+    host_s: float       # ``alloc.flush`` wall time less the solve
+
+
 class Completion(NamedTuple):
     """One answered request (exact-shape, hardened, feasible-by-construction)."""
 
     req_id: int
     alloc: Allocation
     bucket: tuple       # (N_pad, K_pad)
-    latency_s: float    # arrival -> answer (queue wait + batched solve)
-    wait_s: float       # arrival -> flush
+    #: ``wait_s + solve_s``: from the request's arrival to the end of its
+    #: solve, as if the flush did no host work; it leaves out `prepare`, the
+    #: flush's stacking, scoring and unpadding, and the Future's resolution
+    latency_s: float
+    #: arrival -> the flush decision (plus the solves of buckets flushed
+    #: before it in the same round). Through a `RealClockDriver` arrival is
+    #: the enqueue in `submit`, so this includes ``inbox_s``
+    wait_s: float
     solve_s: float      # the batched solve this request rode in
     #: eq. 13 objective of ``alloc``, scored on the padded bucket batch by the
     #: batched kernel (== `system.objective` on the exact-shape scenario to
@@ -163,6 +203,14 @@ class Completion(NamedTuple):
     #: equivalence stays exact even though cache contents are
     #: timing-dependent (batch boundaries move)
     warm_start: CacheEntry | tuple | None = None
+    #: seconds `prepare` took for this request (on the caller's thread)
+    prepare_s: float = 0.0
+    #: seconds between the enqueue in `RealClockDriver.submit` and the solver
+    #: thread's admission (the part of ``wait_s`` spent while the solver
+    #: thread was busy); 0.0 without a driver
+    inbox_s: float = 0.0
+    #: the flush this request rode in (None only for hand-built completions)
+    flush: FlushTiming | None = None
 
 
 class AllocService:
@@ -194,6 +242,7 @@ class AllocService:
         self._acc = default_accuracy()
         self._tenant_acc: dict = {}
         self._next_id = 0
+        self._next_flush = 0
         #: warm-start solution cache (None when disabled). Thread-safe on its
         #: own lock: `prepare` reads it from caller threads, the solver
         #: thread writes it after each flush
@@ -265,29 +314,35 @@ class AllocService:
         ``warm_start`` entry (or tuple of entries) — e.g. the previous FL
         round's solution, or a replay re-injecting recorded hits — takes
         precedence over whatever the cache holds."""
-        w = weights if weights is not None else Weights.ones()
-        acc = self._resolve_accuracy(accuracy, tenant)
-        sig = None
-        if self.warm_cache is not None:
-            sig = request_signature(params, w, acc, self.cfg.warmstart)
-        entry = warm_start
-        # CacheEntry IS a tuple (NamedTuple): only normalise genuine
-        # candidate lists, never a bare entry
-        if isinstance(entry, (list, tuple)) and not isinstance(entry, CacheEntry):
-            entry = tuple(entry) if entry else None
-        if entry is None and self.warm_cache is not None:
-            hits = self.warm_cache.lookup(sig, self.cfg.warmstart.top_k)
-            entry = hits[0] if len(hits) == 1 else (tuple(hits) or None)
-        return PendingRequest(
-            req_id=-1,
-            params=params,
-            padded=self._pad(params),
-            weights=w,
-            arrival_t=0.0,
-            accuracy=acc,
-            warm_start=entry,
-            warm_sig=sig,
-        )
+        with span("alloc.prepare") as sp:
+            w = weights if weights is not None else Weights.ones()
+            acc = self._resolve_accuracy(accuracy, tenant)
+            sig = None
+            entry = warm_start
+            # CacheEntry IS a tuple (NamedTuple): only normalise genuine
+            # candidate lists, never a bare entry
+            if isinstance(entry, (list, tuple)) and not isinstance(entry, CacheEntry):
+                entry = tuple(entry) if entry else None
+            if self.warm_cache is not None:
+                with span("alloc.warm_lookup"):
+                    sig = request_signature(params, w, acc, self.cfg.warmstart)
+                    if entry is None:
+                        hits = self.warm_cache.lookup(sig, self.cfg.warmstart.top_k)
+                        entry = hits[0] if len(hits) == 1 else (tuple(hits) or None)
+            with span("alloc.pad"):
+                padded = self._pad(params)
+            req = PendingRequest(
+                req_id=-1,
+                params=params,
+                padded=padded,
+                weights=w,
+                arrival_t=0.0,
+                accuracy=acc,
+                warm_start=entry,
+                warm_sig=sig,
+            )
+        req.prepare_s = sp.s
+        return req
 
     def admit(self, req: PendingRequest, now: float) -> int:
         """Assign a request id and enqueue a `prepare`d request (arrival
@@ -512,93 +567,118 @@ class AllocService:
         pending = self.batcher.pop(key)
         n_real = len(pending)
         slots = self._slots(n_real)
-        # pad the batch axis by replicating the last request: same shape ->
-        # same executable; replicas are solved and discarded
-        filled = pending + [pending[-1]] * (slots - n_real)
-        pb = stack_params([r.padded for r in filled])
-        wb = stack_weights([r.weights for r in filled])
-        # each row rides ITS OWN A(rho) fit (stamped at `prepare`) as one row
-        # of the stacked runtime accuracy argument — mixed-tenant co-batching
-        # solves and scores every request under its own belief
-        accb = stack_accuracy(
-            [r.accuracy if r.accuracy is not None else self._acc for r in filled]
-        )
-        exe = self._solver(key, slots, pb, wb, accb)
-        # one ExtraStart batch for the flush iff ANY rider has a warm start
-        # (`batch_starts` returns None otherwise): a hitless flush runs the
-        # UNCHANGED cold executable only — the cold==disabled equivalence row
-        # holds per flush, not just per service
-        extra = batch_starts(
-            [r.warm_start for r in filled],
-            [r.padded for r in filled],
-            k=self.cfg.warmstart.top_k if self.cfg.warmstart is not None else None,
-        )
-        if extra is not None:
-            refine = self._refiner(key, slots, pb, wb, accb, extra)
-            extra = self._place_extra(extra)
-        pb, wb, accb = self._place(pb, wb, accb)
-        t0 = time.perf_counter()
-        if extra is None:
-            res = jax.block_until_ready(exe(pb, wb, accb))
-        else:
-            base = exe(pb, wb, accb)
-            res = jax.block_until_ready(refine(pb, wb, accb, extra, base))
-        solve_s = time.perf_counter() - t0
-        self.metrics.observe_batch(n_real, slots, solve_s)
-        # score the padded batch through the batched kernel in one fused call
-        # (outside solve_s: diagnostics, not solver latency) — under the same
-        # per-row fits the rows were SOLVED with, so a `set_accuracy` racing
-        # an in-flight flush can never mis-report `Completion.objective`
-        objs = (
-            np.asarray(self._score(pb, wb, res.alloc, accb))
-            if self.cfg.score_objective
-            else None
-        )
-
-        # convergence traces for the iteration-savings metric (host copy once
-        # per flush, only when warm starts are in play on this service)
-        traces = (
-            np.asarray(res.trace)
-            if (self.cfg.warmstart is not None or extra is not None)
-            else None
-        )
-        iters_rtol = (
-            self.cfg.warmstart.iters_rtol
-            if self.cfg.warmstart is not None
-            else WarmStartConfig().iters_rtol
-        )
-
-        out = []
-        for i, req in enumerate(pending):
-            alloc = unpad_alloc(
-                tree_index(res.alloc, i), req.params.N, req.params.K
-            )
-            obj = float(objs[i]) if objs is not None else None
-            # record the hardened solution for future requests under this
-            # signature (exact shape: one entry serves every covering bucket)
-            if self.warm_cache is not None and req.warm_sig is not None:
-                self.warm_cache.put(req.warm_sig, entry_from_alloc(alloc, obj))
-            if traces is not None:
-                self.metrics.observe_warm(
-                    hit=req.warm_start is not None,
-                    iters=iters_to_converge(traces[i], iters_rtol),
+        flush_id = self._next_flush
+        self._next_flush += 1
+        with span(
+            "alloc.flush", flush_id=flush_id, n_real=n_real, slots=slots,
+            req_ids=" ".join(str(r.req_id) for r in pending),
+        ) as fl:
+            with span("flush.stack") as st:
+                # pad the batch axis by replicating the last request: same
+                # shape -> same executable; replicas are solved and discarded
+                filled = pending + [pending[-1]] * (slots - n_real)
+                pb = stack_params([r.padded for r in filled])
+                wb = stack_weights([r.weights for r in filled])
+                # each row rides ITS OWN A(rho) fit (stamped at `prepare`) as
+                # one row of the stacked runtime accuracy argument —
+                # mixed-tenant co-batching solves and scores every request
+                # under its own belief
+                accb = stack_accuracy(
+                    [r.accuracy if r.accuracy is not None else self._acc for r in filled]
                 )
-            wait = now - req.arrival_t
-            latency = wait + solve_s
-            self.metrics.observe_completion(latency, wait)
-            out.append(
-                Completion(
-                    req_id=req.req_id,
-                    alloc=alloc,
-                    bucket=(key[0], key[1]),
-                    latency_s=latency,
-                    wait_s=wait,
-                    solve_s=solve_s,
-                    objective=obj,
-                    warm_hit=req.warm_start is not None,
-                    warm_start=req.warm_start,
+                exe = self._solver(key, slots, pb, wb, accb)
+                # one ExtraStart batch for the flush iff ANY rider has a warm
+                # start (`batch_starts` returns None otherwise): a hitless
+                # flush runs the UNCHANGED cold executable only — the
+                # cold==disabled equivalence row holds per flush, not just
+                # per service
+                extra = batch_starts(
+                    [r.warm_start for r in filled],
+                    [r.padded for r in filled],
+                    k=self.cfg.warmstart.top_k if self.cfg.warmstart is not None else None,
                 )
+                if extra is not None:
+                    refine = self._refiner(key, slots, pb, wb, accb, extra)
+                    extra = self._place_extra(extra)
+                pb, wb, accb = self._place(pb, wb, accb)
+            with span("flush.solve") as sv:
+                if extra is None:
+                    res = jax.block_until_ready(exe(pb, wb, accb))
+                else:
+                    base = exe(pb, wb, accb)
+                    res = jax.block_until_ready(refine(pb, wb, accb, extra, base))
+            solve_s = sv.s
+            with span("flush.score") as sc:
+                # score the padded batch through the batched kernel in one
+                # fused call (outside solve_s: diagnostics, not solver
+                # latency) — under the same per-row fits the rows were SOLVED
+                # with, so a `set_accuracy` racing an in-flight flush can
+                # never mis-report `Completion.objective`
+                objs = (
+                    [float(v) for v in np.asarray(self._score(pb, wb, res.alloc, accb))]
+                    if self.cfg.score_objective
+                    else [None] * slots
+                )
+            with span("flush.unpad") as up:
+                allocs = [
+                    unpad_alloc(tree_index(res.alloc, i), req.params.N, req.params.K)
+                    for i, req in enumerate(pending)
+                ]
+            with span("flush.record") as rc:
+                self.metrics.observe_batch(n_real, slots, solve_s)
+                # convergence traces for the iteration-savings metric (host
+                # copy once per flush, only when warm starts are in play on
+                # this service)
+                traces = (
+                    np.asarray(res.trace)
+                    if (self.cfg.warmstart is not None or extra is not None)
+                    else None
+                )
+                iters_rtol = (
+                    self.cfg.warmstart.iters_rtol
+                    if self.cfg.warmstart is not None
+                    else WarmStartConfig().iters_rtol
+                )
+                waits, inboxes = [], []
+                for i, (req, alloc) in enumerate(zip(pending, allocs)):
+                    # record the hardened solution for future requests under
+                    # this signature (exact shape: one entry serves every
+                    # covering bucket)
+                    if self.warm_cache is not None and req.warm_sig is not None:
+                        self.warm_cache.put(req.warm_sig, entry_from_alloc(alloc, objs[i]))
+                    if traces is not None:
+                        self.metrics.observe_warm(
+                            hit=req.warm_start is not None,
+                            iters=iters_to_converge(traces[i], iters_rtol),
+                        )
+                    wait = now - req.arrival_t
+                    inbox = req.admit_t - req.arrival_t if req.admit_t is not None else 0.0
+                    self.metrics.observe_completion(
+                        wait + solve_s, wait, req.prepare_s, inbox
+                    )
+                    waits.append(wait)
+                    inboxes.append(inbox)
+        timing = FlushTiming(
+            flush_id, n_real, slots, st.s, sc.s, up.s, rc.s, fl.s - solve_s
+        )
+        self.metrics.observe_flush_host(timing.host_s)
+        out = [
+            Completion(
+                req_id=req.req_id,
+                alloc=alloc,
+                bucket=(key[0], key[1]),
+                latency_s=wait + solve_s,
+                wait_s=wait,
+                solve_s=solve_s,
+                objective=obj,
+                warm_hit=req.warm_start is not None,
+                warm_start=req.warm_start,
+                prepare_s=req.prepare_s,
+                inbox_s=inbox,
+                flush=timing,
             )
+            for req, alloc, obj, wait, inbox in zip(pending, allocs, objs, waits, inboxes)
+        ]
         return out, solve_s
 
     def _flush_while(self, select, now: float) -> tuple[list[Completion], float]:

@@ -78,6 +78,11 @@ def mesh(topo):
 @pytest.fixture
 def pallas_branch(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    yield
+    # a jitted function traced here took the TPU branch, and JAX keeps that
+    # trace by argument shape: drop it, or a later CPU call of the same
+    # function at the same shapes in this process lowers the TPU branch
+    jax.clear_caches()
 
 
 def _abstract(tree, sharding):
