@@ -11,6 +11,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ---------------------------------------------------------------------------
 # unit helpers
@@ -247,6 +248,12 @@ def pad_params(params: SystemParams, n_pad: int, k_pad: int | None = None) -> Sy
     per-subcarrier bandwidth ``bbar = B/K`` — the only way bandwidth enters
     the rate math — is preserved exactly; a padded solve therefore matches
     the exact-shape solve on the real block (asserted in tests).
+
+    The padding runs on the host (numpy) and the padded arrays reach the
+    device in one `jax.device_put`. Eager device padding (a program per
+    array) can wait behind the long programs another thread keeps the device
+    busy with: on a TPU v5e, beside back-to-back 167 ms programs, it took
+    74 ms median against 8.6 ms alone.
     """
     if k_pad is None:
         n_pad, k_pad = n_pad  # a ShapeBucket / (N, K) tuple
@@ -260,10 +267,10 @@ def pad_params(params: SystemParams, n_pad: int, k_pad: int | None = None) -> Sy
     dn, dk = n_pad - params.N, k_pad - params.K
 
     def pad_n(x, fill=0.0):
-        return jnp.pad(x, (0, dn), constant_values=fill)
+        return np.pad(np.asarray(x), (0, dn), constant_values=fill)
 
-    return SystemParams(
-        g=jnp.pad(params.g, ((0, dn), (0, dk))),
+    arrays = dict(
+        g=np.pad(np.asarray(params.g), ((0, dn), (0, dk))),
         c=pad_n(params.c, 1.0),          # value irrelevant: d = 0 zeroes comp terms
         d=pad_n(params.d),
         D=pad_n(params.D),
@@ -272,7 +279,10 @@ def pad_params(params: SystemParams, n_pad: int, k_pad: int | None = None) -> Sy
         f_max=pad_n(params.f_max, 1.0),
         t_sc_max=pad_n(params.t_sc_max, 1.0),
         dev_mask=pad_n(params.dev_mask),
-        sc_mask=jnp.pad(params.sc_mask, (0, dk)),
+        sc_mask=np.pad(np.asarray(params.sc_mask), (0, dk)),
+    )
+    return SystemParams(
+        **jax.device_put(arrays),
         N=n_pad,
         K=k_pad,
         B=params.bbar * k_pad,           # preserve bbar = B/K exactly

@@ -43,6 +43,9 @@ Admission and each flush are timed by `metrics.span`s with fixed names
 `Completion.solve_s` (``flush.solve``) and on one `FlushTiming` per flush,
 shared by its `Completion`s; under a `jax.profiler` session they are also
 host events of the trace.
+
+A flush stacks its rows in one compiled program (`_stack_rows`) and slices
+its answers, in numpy, out of one device->host copy of the solved batch.
 """
 from __future__ import annotations
 
@@ -137,6 +140,17 @@ _flush_objectives.__name__ = _flush_objectives.__qualname__ = "batch_objectives"
 _score_flush = jax.jit(_flush_objectives)
 
 
+@jax.jit
+def _stack_rows(params_rows, weights_rows, acc_rows):
+    """A flush's rows stacked over a new leading batch axis, in one compiled
+    program per (bucket, slots): `stack_params`, `stack_weights` and
+    `stack_accuracy` of the rows, which run eagerly as one device program
+    per leaf and row. `stack_params`' static-meta check runs while tracing,
+    and the meta is part of the program's cache key, so rows of mixed meta
+    always reach it and raise its ``ValueError``."""
+    return stack_params(params_rows), stack_weights(weights_rows), stack_accuracy(acc_rows)
+
+
 @functools.lru_cache(maxsize=None)
 def sharded_score_flush(mesh):
     """`_score_flush` over a scenario mesh: every argument (params, weights,
@@ -170,13 +184,17 @@ class FlushTiming(NamedTuple):
     slots: int          # batch slots solved (padding replicates the last)
     stack_s: float      # stack rows, warm starts, executable lookup, placement
     score_s: float      # the score program and its host copy
-    unpad_s: float      # every request's exact-shape allocation
+    unpad_s: float      # the batch's host copy, every request's exact-shape slice
     record_s: float     # warm-cache puts, convergence counts, metrics
     host_s: float       # ``alloc.flush`` wall time less the solve
 
 
 class Completion(NamedTuple):
-    """One answered request (exact-shape, hardened, feasible-by-construction)."""
+    """One answered request (exact-shape, hardened, feasible-by-construction).
+
+    ``alloc`` holds host numpy arrays, each owning its memory: the flush
+    copies the solved batch to the host once and slices each request's
+    block out, bit for bit the values the device computed."""
 
     req_id: int
     alloc: Allocation
@@ -472,8 +490,9 @@ class AllocService:
         return exe
 
     def _place_extra(self, extra):
-        """Commit a flush's warm-start batch to the device(s) the executables
-        expect (scenario-sharded like the params when running on a mesh)."""
+        """Commit a batch pytree (a flush's warm-start batch; warmup's
+        placeholder allocation) to the device(s) the executables expect
+        (scenario-sharded like the params when running on a mesh)."""
         if self.mesh is None:
             return jax.tree.map(jax.numpy.asarray, extra)
         return jax.device_put(extra, scenario_sharding(self.mesh))
@@ -520,7 +539,9 @@ class AllocService:
 
     def warmup(self, example_params) -> None:
         """Pre-compile executables for the buckets the given example scenarios
-        land in (serving warm-up, so first requests don't pay compile time).
+        land in (serving warm-up, so first requests don't pay compile time):
+        the stack program, the solve, the score program and, with warm
+        starts, the refine program(s).
 
         With ``pad_batch=True`` (default) every flush uses ``max_batch`` slots,
         so one compile per bucket covers steady state. With ``pad_batch=False``
@@ -534,10 +555,22 @@ class AllocService:
             seen.setdefault(self._bucket_key(padded), padded)
         slots = self._slots(1)
         for key, padded in seen.items():
-            pb = stack_params([padded] * slots)
-            wb = stack_weights([Weights.ones()] * slots)
-            accb = stack_accuracy([self._acc] * slots)
+            pb, wb, accb = _stack_rows(
+                [padded] * slots, [Weights.ones()] * slots, [self._acc] * slots
+            )
             self._solver(key, slots, pb, wb, accb)
+            if self.cfg.score_objective:
+                # the score program takes the solve's allocation: zeros of
+                # its shapes, placed as a flush places its batch, compile it
+                n, k = padded.N, padded.K
+                zeros = Allocation(
+                    f=np.zeros((slots, n), np.float32),
+                    P=np.zeros((slots, n, k), np.float32),
+                    X=np.zeros((slots, n, k), np.float32),
+                    rho=np.zeros((slots,), np.float32),
+                )
+                pbp, wbp, accbp = self._place(pb, wb, accb)
+                self._score(pbp, wbp, self._place_extra(zeros), accbp)
             if self.cfg.warmstart is not None:
                 # pre-compile the warm-refine program(s) too (a placeholder
                 # entry fixes the shapes; contents are irrelevant to tracing)
@@ -577,14 +610,14 @@ class AllocService:
                 # pad the batch axis by replicating the last request: same
                 # shape -> same executable; replicas are solved and discarded
                 filled = pending + [pending[-1]] * (slots - n_real)
-                pb = stack_params([r.padded for r in filled])
-                wb = stack_weights([r.weights for r in filled])
                 # each row rides ITS OWN A(rho) fit (stamped at `prepare`) as
                 # one row of the stacked runtime accuracy argument —
                 # mixed-tenant co-batching solves and scores every request
                 # under its own belief
-                accb = stack_accuracy(
-                    [r.accuracy if r.accuracy is not None else self._acc for r in filled]
+                pb, wb, accb = _stack_rows(
+                    [r.padded for r in filled],
+                    [r.weights for r in filled],
+                    [r.accuracy if r.accuracy is not None else self._acc for r in filled],
                 )
                 exe = self._solver(key, slots, pb, wb, accb)
                 # one ExtraStart batch for the flush iff ANY rider has a warm
@@ -620,8 +653,15 @@ class AllocService:
                     else [None] * slots
                 )
             with span("flush.unpad") as up:
+                # one device->host copy of the batch, then numpy slices; each
+                # answer copies its block out, so neither it nor a warm-cache
+                # entry made from it keeps the whole batch alive
+                host = jax.device_get(res.alloc)
                 allocs = [
-                    unpad_alloc(tree_index(res.alloc, i), req.params.N, req.params.K)
+                    jax.tree.map(
+                        np.copy,
+                        unpad_alloc(tree_index(host, i), req.params.N, req.params.K),
+                    )
                     for i, req in enumerate(pending)
                 ]
             with span("flush.record") as rc:

@@ -64,6 +64,34 @@ def test_pad_params_identity_and_reject_shrink():
         pad_params(p, 3, 12)
 
 
+def _device_pad(p, n_pad, k_pad):
+    """The eager device padding `pad_params` replaced (the reference)."""
+    dn, dk = n_pad - p.N, k_pad - p.K
+    pn = lambda x, fill=0.0: jnp.pad(x, (0, dn), constant_values=fill)  # noqa: E731
+    return dict(
+        g=jnp.pad(p.g, ((0, dn), (0, dk))), c=pn(p.c, 1.0), d=pn(p.d), D=pn(p.D),
+        C=pn(p.C), p_max=pn(p.p_max, 1.0), f_max=pn(p.f_max, 1.0),
+        t_sc_max=pn(p.t_sc_max, 1.0), dev_mask=pn(p.dev_mask),
+        sc_mask=jnp.pad(p.sc_mask, (0, dk)),
+    )
+
+
+@pytest.mark.parametrize("leaves", ["device", "host_float32", "host_float64"])
+def test_pad_params_host_padding_equals_device_padding(leaves):
+    """Host padding gives the eager device padding's arrays bit for bit, on
+    the device, whatever the request's arrays are."""
+    p = sample_params(jax.random.PRNGKey(4), N=3, K=8)
+    if leaves != "device":
+        dtype = np.float32 if leaves == "host_float32" else np.float64
+        p = jax.tree.map(lambda x: np.asarray(x, dtype), p)
+    pp = pad_params(p, 4, 12)
+    for name, want in _device_pad(p, 4, 12).items():
+        got = getattr(pp, name)
+        assert isinstance(got, jax.Array)
+        assert (got.dtype, got.shape, got.weak_type) == (want.dtype, want.shape, want.weak_type)
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
 def test_bucket_for_picks_smallest_fit():
     assert bucket_for(3, 8) == ShapeBucket(4, 8)
     assert bucket_for(4, 12) == ShapeBucket(4, 16)

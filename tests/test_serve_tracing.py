@@ -90,6 +90,8 @@ def test_driver_completions_carry_timings(executables):
         parts = f.stack_s + f.score_s + f.unpad_s + f.record_s
         assert min(f.stack_s, c.solve_s, f.score_s, f.unpad_s, f.record_s) >= 0.0
         assert parts <= f.host_s + EPS  # children within the flush's wall
+        # the stack and the unpad each lie inside ``alloc.flush``'s wall
+        assert max(f.stack_s, f.unpad_s) <= f.host_s + c.solve_s + EPS
         by_flush[f.flush_id].append(c)
     for fid, group in by_flush.items():
         assert len({id(c.flush) for c in group}) == 1   # one record per flush
